@@ -107,6 +107,8 @@ def main() -> None:
         print(f"unknown benchmark(s): {unknown}; have {list(MODULES)}",
               file=sys.stderr)
         raise SystemExit(2)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache(ROOT)
     print("name,value,derived")
     failed = []
     for name in names:

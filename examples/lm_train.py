@@ -6,6 +6,7 @@ sharding rules -> fused-xent train step -> trainer with checkpoints.
 """
 
 import argparse
+import tempfile
 import dataclasses
 
 import jax
@@ -25,7 +26,9 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--ckpt", default="/tmp/repro_lm_ckpt")
+    ap.add_argument("--ckpt", default=None,
+                    help="checkpoint dir to resume from and save to "
+                         "(default: a fresh temporary dir)")
     args = ap.parse_args()
 
     cfg = configs.get_smoke_config(args.arch)
@@ -43,7 +46,7 @@ def main() -> None:
         return p, opt.init(p)
 
     tr = Trainer(TrainerConfig(total_steps=args.steps, ckpt_every=25,
-                               ckpt_dir=args.ckpt),
+                               ckpt_dir=args.ckpt or tempfile.mkdtemp()),
                  train_step=step, init_state=init_state, batch_fn=ts.batch)
     res = tr.run()
     import math

@@ -21,9 +21,13 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--tokens", type=int, default=16)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced config (--no-smoke: published widths)")
     args = ap.parse_args()
 
-    cfg = configs.get_smoke_config(args.arch)
+    cfg = (configs.get_smoke_config(args.arch) if args.smoke
+           else configs.get_config(args.arch))
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     max_len = args.prompt_len + args.tokens
@@ -47,7 +51,8 @@ def main() -> None:
     dt = time.perf_counter() - t0
     gen = jnp.stack(out, 1)
     print(f"{args.arch}: generated {gen.shape} tokens in {dt:.2f}s "
-          f"({args.batch*args.tokens/dt:.1f} tok/s on CPU smoke config)")
+          f"({args.batch*args.tokens/dt:.1f} tok/s on "
+          f"{jax.devices()[0].platform}, {cfg.name})")
     print(gen[0][:12])
 
 

@@ -7,6 +7,7 @@ and the PIM accelerator cost of the training run (Fig. 6 pipeline).
 """
 
 import argparse
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=64)
-    ap.add_argument("--ckpt", default="/tmp/repro_lenet_ckpt")
+    ap.add_argument("--ckpt", default=None,
+                    help="checkpoint dir to resume from and save to "
+                         "(default: a fresh temporary dir)")
     args = ap.parse_args()
 
     opt = make_optimizer("adamw", lr=2e-3)
@@ -41,7 +44,7 @@ def main() -> None:
         return params, opt_state, loss
 
     tr = Trainer(TrainerConfig(total_steps=args.steps, ckpt_every=50,
-                               ckpt_dir=args.ckpt),
+                               ckpt_dir=args.ckpt or tempfile.mkdtemp()),
                  train_step=train_step, init_state=init_state,
                  batch_fn=ds.batch)
     res = tr.run()

@@ -63,6 +63,10 @@ GATES = {
     },
 }
 
+# gated variants that may instead record why they were not measured
+# (the in-process async pipeline needs 4 devices)
+MAY_BE_UNMEASURED = {("BENCH_pipeline", "llama3_8b_async_measured")}
+
 
 def _check_provenance(path: pathlib.Path, data: dict,
                       errors: list[str]) -> None:
@@ -98,6 +102,9 @@ def _check(path: pathlib.Path, errors: list[str]) -> None:
         if not isinstance(block, dict):
             errors.append(f"{path.name}: missing gated variant "
                           f"{variant!r}")
+            continue
+        if (path.stem, variant) in MAY_BE_UNMEASURED \
+                and isinstance(block.get("not_measured"), str):
             continue
         for f in fields:
             v = block.get(f)

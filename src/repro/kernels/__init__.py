@@ -16,10 +16,11 @@
                                    table (``..._q``: same launch over a
                                    quantized pool, dequantize-on-load)
 
-``ops`` holds the jit'd public wrappers; ``ref`` the pure-jnp oracles.
+Each kernel picks its mode itself (``repro.kernels.mode``): compiled on a
+TPU, interpreted elsewhere. ``ref`` holds the pure-jnp oracles.
 """
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
 from repro.kernels.flash_attention import (flash_attention,
                                            paged_decode_attention_grouped,
                                            paged_decode_attention_grouped_q)
@@ -28,7 +29,7 @@ from repro.kernels.pim_mac import (pim_mac, pim_mac_grouped, pim_matmul,
                                    pim_matmul_grouped,
                                    pim_matmul_grouped_q)
 
-__all__ = ["ops", "ref", "flash_attention", "paged_decode_attention_grouped",
+__all__ = ["ref", "flash_attention", "paged_decode_attention_grouped",
            "paged_decode_attention_grouped_q",
            "pim_fp32_mul", "pim_mac", "pim_mac_grouped", "pim_matmul",
            "pim_matmul_grouped", "pim_matmul_grouped_q"]

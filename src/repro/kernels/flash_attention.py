@@ -36,7 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quant
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.mode import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +82,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                    static_argnames=("q_chunk", "kv_chunk", "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     q_chunk: int = 256, kv_chunk: int = 256,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool | None = None) -> jnp.ndarray:
     """q: [B,S,H,D]; k/v: [B,S,G,D] -> [B,S,H,D] (causal)."""
     b, s, h, d = q.shape
     g = k.shape[2]
@@ -117,10 +117,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((qc, 1), jnp.float32),
             pltpu.VMEM((qc, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return jnp.moveaxis(out, 1, 2)        # [B,S,H,D]
 
@@ -149,8 +149,8 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(w * bs <= p)
     def _compute():
         q = q_ref[0, 0]                    # [R, D]
-        k = k_ref[0, :, 0, :]              # [bs, D]
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0]                       # [bs, D]: this kv head's lanes
+        v = v_ref[0]
         sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         k_pos = w * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         sc = jnp.where(k_pos <= p, sc, NEG_INF)
@@ -175,7 +175,8 @@ def paged_decode_attention_grouped(q: jnp.ndarray, k_store: jnp.ndarray,
                                    v_store: jnp.ndarray,
                                    block_table: jnp.ndarray,
                                    pos: jnp.ndarray, *,
-                                   interpret: bool = True) -> jnp.ndarray:
+                                   interpret: bool | None = None
+                                   ) -> jnp.ndarray:
     """Decode attention over a paged KV pool for all slots in one launch.
 
     q: [B, H, D] (one new token per slot); k/v_store: [N, bs, G, D] (the
@@ -190,6 +191,10 @@ def paged_decode_attention_grouped(q: jnp.ndarray, k_store: jnp.ndarray,
     scalar-prefetch — the k/v index map reads ``block_table[b, w]`` — so
     the gather happens in the kernel's block streaming, not as a
     per-slot XLA gather chain.
+
+    The pool is viewed as ``[N, bs, G * D]`` (a free reshape), so one kv
+    head's share of a block is a ``(bs, D)`` tile on a 128-lane boundary:
+    the block shape the TPU lowering accepts, with the same bytes read.
     """
     b, h, d = q.shape
     n_blocks, bs, g, _ = k_store.shape
@@ -197,21 +202,20 @@ def paged_decode_attention_grouped(q: jnp.ndarray, k_store: jnp.ndarray,
     rep = h // g
     scale = 1.0 / math.sqrt(d)
     qg = q.reshape(b, g, rep, d)
+    kv_spec = pl.BlockSpec((1, bs, d), lambda ib, ig, iw, tbl, pos:
+                           (tbl[ib, iw], 0, ig))
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, bs=bs, n_w=w, scale=scale),
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, g, w),
             in_specs=[
                 pl.BlockSpec((1, 1, rep, d),
                              lambda ib, ig, iw, tbl, pos: (ib, ig, 0, 0)),
-                pl.BlockSpec((1, bs, 1, d),
-                             lambda ib, ig, iw, tbl, pos:
-                             (tbl[ib, iw], 0, ig, 0)),
-                pl.BlockSpec((1, bs, 1, d),
-                             lambda ib, ig, iw, tbl, pos:
-                             (tbl[ib, iw], 0, ig, 0)),
+                kv_spec,
+                kv_spec,
             ],
             out_specs=pl.BlockSpec((1, 1, rep, d),
                                    lambda ib, ig, iw, tbl, pos:
@@ -223,18 +227,28 @@ def paged_decode_attention_grouped(q: jnp.ndarray, k_store: jnp.ndarray,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, g, rep, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), pos.astype(jnp.int32), qg, k_store,
-      v_store)
+        interpret=resolve_interpret(interpret),
+    )(block_table.astype(jnp.int32), pos.astype(jnp.int32), qg,
+      k_store.reshape(n_blocks, bs, g * d),
+      v_store.reshape(n_blocks, bs, g * d))
     return out.reshape(b, h, d)
+
+
+def _head_scale(scales: jnp.ndarray, g) -> jnp.ndarray:
+    """Column ``g`` of a block's ``(bs, G)`` scales as ``(bs, 1)``: a
+    masked sum, exact because every other term is zero, that avoids a
+    dynamic lane index."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 1)
+    return jnp.sum(jnp.where(cols == g, scales, 0.0), axis=1, keepdims=True)
 
 
 def _paged_decode_kernel_q(tbl_ref, pos_ref, q_ref, k_ref, ks_ref, v_ref,
                            vs_ref, o_ref, acc_ref, m_ref, l_ref, *, bs: int,
                            n_w: int, scale: float, kv_dtype: str):
     b = pl.program_id(0)
+    g = pl.program_id(1)
     w = pl.program_id(2)
 
     @pl.when(w == 0)
@@ -251,9 +265,9 @@ def _paged_decode_kernel_q(tbl_ref, pos_ref, q_ref, k_ref, ks_ref, v_ref,
         # dequantize on load: the streamed KV block is packed codes plus
         # one f32 scale per token — the same decode the XLA oracle path
         # runs, so grouped-vs-oracle stays bit-identical.
-        k = quant.dequantize_kv(k_ref[0, :, 0, :], ks_ref[0, :, 0, :],
+        k = quant.dequantize_kv(k_ref[0], _head_scale(ks_ref[0], g),
                                 kv_dtype)
-        v = quant.dequantize_kv(v_ref[0, :, 0, :], vs_ref[0, :, 0, :],
+        v = quant.dequantize_kv(v_ref[0], _head_scale(vs_ref[0], g),
                                 kv_dtype)
         sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         k_pos = w * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
@@ -281,7 +295,8 @@ def paged_decode_attention_grouped_q(q: jnp.ndarray, k_store: jnp.ndarray,
                                      v_scale: jnp.ndarray,
                                      block_table: jnp.ndarray,
                                      pos: jnp.ndarray, *, kv_dtype: str,
-                                     interpret: bool = True) -> jnp.ndarray:
+                                     interpret: bool | None = None
+                                     ) -> jnp.ndarray:
     """:func:`paged_decode_attention_grouped` over a *quantized* KV pool.
 
     k/v_store hold packed absmax-scaled codes ([N, bs, G, D] int8 /
@@ -290,7 +305,10 @@ def paged_decode_attention_grouped_q(q: jnp.ndarray, k_store: jnp.ndarray,
     the same scalar-prefetched block-table index maps, and the kernel
     dequantizes each block on load with f32 score/softmax accumulation —
     the activation-side mirror of ``pim_matmul_grouped_q``'s
-    dequantize-on-load weight path.
+    dequantize-on-load weight path. Codes stream as ``(bs, D)`` tiles of
+    the ``[N, bs, G * D]`` view, as in the unquantized kernel; a block's
+    scales stream for all G heads at once (``(bs, G)``, full extent) and
+    the kernel picks its head's column.
     """
     b, h, d = q.shape
     n_blocks, bs, g, _ = k_store.shape
@@ -299,20 +317,24 @@ def paged_decode_attention_grouped_q(q: jnp.ndarray, k_store: jnp.ndarray,
     scale = 1.0 / math.sqrt(d)
     qg = q.reshape(b, g, rep, d)
 
-    kv_map = lambda ib, ig, iw, tbl, pos: (tbl[ib, iw], 0, ig, 0)
+    code_spec = pl.BlockSpec((1, bs, d), lambda ib, ig, iw, tbl, pos:
+                             (tbl[ib, iw], 0, ig))
+    scale_spec = pl.BlockSpec((1, bs, g), lambda ib, ig, iw, tbl, pos:
+                              (tbl[ib, iw], 0, 0))
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel_q, bs=bs, n_w=w, scale=scale,
                           kv_dtype=quant.spec(kv_dtype).name),
+        name="paged_decode_attention_q",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, g, w),
             in_specs=[
                 pl.BlockSpec((1, 1, rep, d),
                              lambda ib, ig, iw, tbl, pos: (ib, ig, 0, 0)),
-                pl.BlockSpec((1, bs, 1, d), kv_map),
-                pl.BlockSpec((1, bs, 1, 1), kv_map),
-                pl.BlockSpec((1, bs, 1, d), kv_map),
-                pl.BlockSpec((1, bs, 1, 1), kv_map),
+                code_spec,
+                scale_spec,
+                code_spec,
+                scale_spec,
             ],
             out_specs=pl.BlockSpec((1, 1, rep, d),
                                    lambda ib, ig, iw, tbl, pos:
@@ -324,9 +346,10 @@ def paged_decode_attention_grouped_q(q: jnp.ndarray, k_store: jnp.ndarray,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, g, rep, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), pos.astype(jnp.int32), qg, k_store,
-      k_scale, v_store, v_scale)
+        interpret=resolve_interpret(interpret),
+    )(block_table.astype(jnp.int32), pos.astype(jnp.int32), qg,
+      k_store.reshape(n_blocks, bs, g * d), k_scale.reshape(n_blocks, bs, g),
+      v_store.reshape(n_blocks, bs, g * d), v_scale.reshape(n_blocks, bs, g))
     return out.reshape(b, h, d)

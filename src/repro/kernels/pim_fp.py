@@ -22,6 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mode import resolve_interpret
+
+
 def _pim_fp32_mul_kernel(a_ref, b_ref, o_ref):
     # masks built in-kernel (module-level jnp constants would be captured
     # as consts, which pallas_call rejects)
@@ -90,7 +93,7 @@ def _pim_fp32_mul_kernel(a_ref, b_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def pim_fp32_mul(a: jnp.ndarray, b: jnp.ndarray, *, block: int = 1024,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """Elementwise bit-exact f32 multiply via the PIM shift-and-add."""
     assert a.shape == b.shape
     orig = a.shape
@@ -107,6 +110,6 @@ def pim_fp32_mul(a: jnp.ndarray, b: jnp.ndarray, *, block: int = 1024,
         in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))] * 2,
         out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, block), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a2, b2)
     return out.reshape(-1)[:n].reshape(orig)

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.mode import resolve_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -68,10 +68,19 @@ def _mac_kernel(a_ref, b_ref, acc_ref, o_ref):
     o_ref[...] = acc_ref[...] + a_ref[...] * b_ref[...]
 
 
+# rows of ``block`` lanes per grid step: a multiple of every dtype's
+# sublane tile (8 f32, 16 bf16, 32 int8), so the block obeys the TPU's
+# 8x128 rule; waves of at most this many rows take one full-extent step
+_MAC_ROWS = 128
+
+
 def _mac_call(a, b, acc, block: int, interpret: bool) -> jnp.ndarray:
     orig_shape = a.shape
     n = a.size
-    pad = (-n) % block
+    rows = -(-n // block)
+    tr = min(rows, _MAC_ROWS)
+    rows = -(-rows // tr) * tr           # whole row tiles
+    pad = rows * block - n
     aligned = not pad and a.ndim == 2 and a.shape[1] == block
 
     def prep(x):
@@ -83,12 +92,11 @@ def _mac_call(a, b, acc, block: int, interpret: bool) -> jnp.ndarray:
         return x.reshape(-1, block)
 
     a2, b2, acc2 = prep(a), prep(b), prep(acc)
-    rows = a2.shape[0]
     out = pl.pallas_call(
         _mac_kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))] * 3,
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
+        grid=(rows // tr,),
+        in_specs=[pl.BlockSpec((tr, block), lambda i: (i, 0))] * 3,
+        out_specs=pl.BlockSpec((tr, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, block), acc.dtype),
         interpret=interpret,
     )(a2, b2, acc2)
@@ -122,15 +130,16 @@ _pim_mac_vjp.defvjp(_pim_mac_fwd, _pim_mac_bwd)
 
 
 def pim_mac(a: jnp.ndarray, b: jnp.ndarray, acc: jnp.ndarray,
-            *, block: int = 1024, interpret: bool = True) -> jnp.ndarray:
+            *, block: int = 1024,
+            interpret: bool | None = None) -> jnp.ndarray:
     """Elementwise acc + a*b, tiled along the last dim. Differentiable
     (custom VJP; cotangents are pim_mac calls)."""
     assert a.shape == b.shape == acc.shape
-    return _pim_mac_vjp(a, b, acc, block, interpret)
+    return _pim_mac_vjp(a, b, acc, block, resolve_interpret(interpret))
 
 
 def pim_mac_grouped(triples, *, block: int = 1024,
-                    interpret: bool = True) -> list:
+                    interpret: bool | None = None) -> list:
     """One kernel launch for a *wave* of independent eltwise MACs.
 
     ``triples`` is a sequence of same-dtype ``(a, b, acc)`` triples of
@@ -143,6 +152,7 @@ def pim_mac_grouped(triples, *, block: int = 1024,
     VJP (whose cotangents are two more grouped launches).
     """
     triples = list(triples)
+    interpret = resolve_interpret(interpret)
     assert triples, "pim_mac_grouped needs at least one (a, b, acc) triple"
     shapes = [a.shape for a, _, _ in triples]
     sizes = [a.size for a, _, _ in triples]
@@ -198,7 +208,7 @@ def _matmul_call(a, b, bm: int, bn: int, bk: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -229,10 +239,10 @@ _pim_matmul_vjp.defvjp(_pim_matmul_fwd, _pim_matmul_bwd)
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def pim_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
                bn: int = 128, bk: int = 128,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool | None = None) -> jnp.ndarray:
     """f32 C = A @ B with (bm, bn, bk) VMEM tiles (MXU-aligned on TPU).
     Differentiable (custom VJP; both cotangents are pim_matmul calls)."""
-    return _pim_matmul_vjp(a, b, bm, bn, bk, interpret)
+    return _pim_matmul_vjp(a, b, bm, bn, bk, resolve_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +287,7 @@ def _matmul_grouped_call(a, b, bm: int, bn: int, bk: int,
         out_specs=pl.BlockSpec((1, bm, bn), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -318,7 +328,7 @@ _pim_matmul_grouped_vjp.defvjp(_pim_matmul_grouped_fwd,
                                              "col_groups"))
 def pim_matmul_grouped(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
                        bn: int = 128, bk: int = 128,
-                       interpret: bool = True,
+                       interpret: bool | None = None,
                        col_groups: int = 1) -> jnp.ndarray:
     """f32 ``C[g] = A[g // col_groups] @ B[g]`` for a stack of G = len(B)
     block operands in ONE ``pallas_call`` (grid ``(G, M/bm, N/bn,
@@ -338,7 +348,8 @@ def pim_matmul_grouped(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
     Each group's K-axis accumulation order and tile shapes are identical
     to a standalone ``pim_matmul`` on the same padded operands, so
     grouped results are bit-identical to the per-block path."""
-    return _pim_matmul_grouped_vjp(a, b, bm, bn, bk, interpret, col_groups)
+    return _pim_matmul_grouped_vjp(a, b, bm, bn, bk,
+                                   resolve_interpret(interpret), col_groups)
 
 
 # ---------------------------------------------------------------------------
@@ -387,7 +398,7 @@ def _matmul_grouped_q_call(a, q, s, bm: int, bn: int, bk: int,
         out_specs=pl.BlockSpec((1, bm, bn), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -432,7 +443,7 @@ _pim_matmul_grouped_q_vjp.defvjp(_pim_matmul_grouped_q_fwd,
                                              "col_groups"))
 def pim_matmul_grouped_q(a: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray, *,
                          bm: int = 128, bn: int = 128, bk: int = 128,
-                         interpret: bool = True,
+                         interpret: bool | None = None,
                          col_groups: int = 1) -> jnp.ndarray:
     """``pim_matmul_grouped`` over quantized stored weights:
     ``C[g] = A[g // col_groups] @ (Q[g] * S[g])`` in one launch.
@@ -448,5 +459,6 @@ def pim_matmul_grouped_q(a: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray, *,
     so results are bit-identical to the per-block oracle running on
     pre-dequantized blocks. Differentiable: see
     ``_pim_matmul_grouped_q_bwd``."""
-    return _pim_matmul_grouped_q_vjp(a, q, s, bm, bn, bk, interpret,
+    return _pim_matmul_grouped_q_vjp(a, q, s, bm, bn, bk,
+                                     resolve_interpret(interpret),
                                      col_groups)

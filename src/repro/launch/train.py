@@ -3,12 +3,13 @@ trainer. On real hardware the production mesh spans pods; on this host it
 runs reduced (smoke) configs on the local device mesh.
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3-8b \
-        --steps 50 [--smoke] [--model-axis 1]
+        --steps 50 [--no-smoke] [--model-axis 1]
 """
 
 from __future__ import annotations
 
 import argparse
+import tempfile
 
 import jax
 
@@ -30,11 +31,15 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--smoke", action="store_true", default=True,
-                    help="reduced config (full configs need a TPU fleet)")
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced config (--no-smoke: published widths, "
+                         "which need a TPU fleet)")
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--model-axis", type=int, default=1)
-    ap.add_argument("--ckpt", default="/tmp/repro_launch_ckpt")
+    ap.add_argument("--ckpt", default=None,
+                    help="checkpoint dir to resume from and save to "
+                         "(default: a fresh temporary dir)")
     args = ap.parse_args()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
@@ -69,7 +74,7 @@ def main() -> None:
 
     with mesh, sharding.use_rules(rules):
         tr = Trainer(TrainerConfig(total_steps=args.steps, ckpt_every=25,
-                                   ckpt_dir=args.ckpt),
+                                   ckpt_dir=args.ckpt or tempfile.mkdtemp()),
                      train_step=step, init_state=init_state,
                      batch_fn=ts.batch)
         res = tr.run()

@@ -151,7 +151,8 @@ def compile_arch(name: str, kind: str = "train", *, seq_len: int = 128,
                  tech: str = "proposed", weight_dtype: str = "fp32",
                  act_dtype: str = "fp32",
                  block: int = 128,
-                 interpret: bool = True, partitions: int | None = None,
+                 interpret: bool | None = None,
+                 partitions: int | None = None,
                  expand_scans: bool = False, devices=None):
     """Map one architecture's step and compile it to a jittable program
     (a ``PartitionedProgram`` of K stage programs when ``partitions=K``;
@@ -175,7 +176,8 @@ def compile_lenet(kind: str = "serve", *, batch: int = 4, lr: float = 0.05,
                   tech: str = "proposed", weight_dtype: str = "fp32",
                   act_dtype: str = "fp32",
                   block: int = 128,
-                  interpret: bool = True, partitions: int | None = None,
+                  interpret: bool | None = None,
+                  partitions: int | None = None,
                   devices=None):
     """Map the paper's LeNet and compile it to a jittable program
     (a ``PartitionedProgram`` of K stage programs when ``partitions=K``;

@@ -58,9 +58,11 @@ import dataclasses
 from typing import Any, Callable
 
 import jax
+from jax.extend.core import Literal
 import numpy as np
 
 from repro import obs
+from repro.kernels.mode import resolve_interpret
 from repro.mapper import placement as placement_mod
 from repro.mapper.lowering import LoweringContext, eval_eqns, eval_placed
 from repro.mapper.schedule import Schedule
@@ -194,7 +196,7 @@ def clear_program_cache() -> None:
 
 
 def compile_schedule(schedule: Schedule, *, block: int = 128,
-                     interpret: bool = True, group: bool = True,
+                     interpret: bool | None = None, group: bool = True,
                      fuse: bool = True,
                      use_cache: bool = True) -> CompiledProgram:
     """Lower ``schedule`` into one jittable, differentiable function.
@@ -207,6 +209,7 @@ def compile_schedule(schedule: Schedule, *, block: int = 128,
     calls replay the compiled executable. ``group=False, fuse=False``
     bakes the legacy one-launch-per-block program instead.
     """
+    interpret = resolve_interpret(interpret)
     if use_cache:
         key = _program_key(schedule, block, interpret, group, fuse)
         hit = _CACHE.get(key)
@@ -289,6 +292,17 @@ class StageProgram:
                                   # inputs onto the stage's own async queue
 
 
+def _resolve(ref: tuple, flat: list, stage_outs: list):
+    """Value behind a transfer reference: ``("arg", i)`` is program
+    argument i, ``("stage", s, j)`` output j of stage s, ``("lit", v)``
+    a literal."""
+    if ref[0] == "arg":
+        return flat[ref[1]]
+    if ref[0] == "stage":
+        return stage_outs[ref[1]][ref[2]]
+    return ref[1]
+
+
 @dataclasses.dataclass
 class PartitionedProgram:
     """A schedule compiled as one jittable program per pipeline partition.
@@ -339,21 +353,24 @@ class PartitionedProgram:
         ``self(*args)`` (same stage programs, same order); callers
         observe values (or ``jax.block_until_ready``) to sync."""
         flat = self.flatten_args(*args, **kwargs)
+        stage_outs = self._run_stages_async(flat)
+        return self.unflatten_outs([_resolve(r, flat, stage_outs)
+                                    for r in self.out_refs])
+
+    def run_stages_async(self, *args, **kwargs) -> list[tuple]:
+        """:meth:`run_async`, returning each stage's own outputs (stage
+        order) instead of the program's — each lives on its stage's
+        pinned device, which is how a caller checks the placement."""
+        return self._run_stages_async(self.flatten_args(*args, **kwargs))
+
+    def _run_stages_async(self, flat: list) -> list[tuple]:
         stage_outs: list[tuple] = []
-
-        def resolve(ref):
-            if ref[0] == "arg":
-                return flat[ref[1]]
-            if ref[0] == "stage":
-                return stage_outs[ref[1]][ref[2]]
-            return ref[1]                  # ("lit", val)
-
         for st in self.stages:
-            ins = [resolve(r) for r in st.in_refs]
+            ins = [_resolve(r, flat, stage_outs) for r in st.in_refs]
             if st.device is not None:
                 ins = [jax.device_put(x, st.device) for x in ins]
             stage_outs.append(st.jitted(*ins))
-        return self.unflatten_outs([resolve(r) for r in self.out_refs])
+        return stage_outs
 
     @property
     def placed_blocks(self) -> int:
@@ -414,7 +431,7 @@ def _aval_bits(v) -> int:
 
 def compile_partitioned(schedule: Schedule, *,
                         partitions: int | None = None, block: int = 128,
-                        interpret: bool = True, group: bool = True,
+                        interpret: bool | None = None, group: bool = True,
                         fuse: bool = True, use_cache: bool = True,
                         devices=None) -> PartitionedProgram:
     """Lower ``schedule`` into one jittable program per pipeline partition.
@@ -443,6 +460,7 @@ def compile_partitioned(schedule: Schedule, *,
             "build_schedule(..., partitions=K) or pass partitions=K")
     boundaries = tuple((p.eqn_start, p.eqn_end) for p in parts)
     dev_ring = tuple(devices) if devices else ()
+    interpret = resolve_interpret(interpret)
 
     if use_cache:
         key = _program_key(schedule, block, interpret, group, fuse,
@@ -468,10 +486,10 @@ def compile_partitioned(schedule: Schedule, *,
     last_read: dict[Any, int] = {}
     for e, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 last_read[v] = e
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, Literal):
             last_read[v] = len(jaxpr.eqns)
 
     holder: list[PartitionedProgram] = []
@@ -484,7 +502,7 @@ def compile_partitioned(schedule: Schedule, *,
         stage_consts: dict = {}
         for eqn in eqns:
             for v in eqn.invars:
-                if isinstance(v, jax.core.Literal) or v in inner_prod:
+                if isinstance(v, Literal) or v in inner_prod:
                     continue
                 if v in consts_by_var:
                     stage_consts[v] = consts_by_var[v]
@@ -520,7 +538,7 @@ def compile_partitioned(schedule: Schedule, *,
 
     out_refs: list[tuple] = []
     for v in jaxpr.outvars:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             out_refs.append(("lit", v.val))
         elif v in invar_idx:
             out_refs.append(("arg", invar_idx[v]))
@@ -536,17 +554,11 @@ def compile_partitioned(schedule: Schedule, *,
         if holder and any(isinstance(x, jax.core.Tracer) for x in flat):
             holder[0].trace_count += 1
         stage_outs: list[tuple] = []
-
-        def resolve(ref):
-            if ref[0] == "arg":
-                return flat[ref[1]]
-            if ref[0] == "stage":
-                return stage_outs[ref[1]][ref[2]]
-            return ref[1]                      # ("lit", val)
-
         for st in stages:
-            stage_outs.append(st.fn(*[resolve(r) for r in st.in_refs]))
-        return program.unflatten_outs([resolve(r) for r in out_refs])
+            stage_outs.append(st.fn(*[_resolve(r, flat, stage_outs)
+                                      for r in st.in_refs]))
+        return program.unflatten_outs([_resolve(r, flat, stage_outs)
+                                       for r in out_refs])
 
     program.fn = fn
     program.jitted = jax.jit(fn)

@@ -47,7 +47,7 @@ class ScheduleExecutor:
     """
 
     schedule: Schedule
-    interpret: bool = True
+    interpret: bool | None = None   # None: compiled on a TPU only
     block: int = 128              # pallas tile edge (pad-to multiple)
     group: bool = False
     fuse: bool = False
@@ -123,6 +123,7 @@ class ScheduleExecutor:
         return worst
 
 
-def run_schedule(schedule: Schedule, *args, interpret: bool = True, **kwargs):
+def run_schedule(schedule: Schedule, *args, interpret: bool | None = None,
+                 **kwargs):
     """One-shot: execute ``schedule`` on concrete inputs."""
     return ScheduleExecutor(schedule, interpret=interpret).run(*args, **kwargs)

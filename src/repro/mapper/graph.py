@@ -30,6 +30,7 @@ import math
 from typing import Any, Callable
 
 import jax
+from jax.extend.core import Literal, jaxpr_as_fun
 import jax.numpy as jnp
 import numpy as np
 
@@ -142,8 +143,9 @@ def build_graph_from_jaxpr(closed_jaxpr, in_tree=None, out_tree=None,
             if eqn.invars else frozenset()
         node: OpNode | None = None
         idx = len(nodes)
-        out_shape = tuple(eqn.outvars[0].aval.shape)
         kind = estimator.node_kind(name)
+        # some equations have no outputs (no placed kind among them)
+        out_shape = tuple(eqn.outvars[0].aval.shape) if eqn.outvars else ()
         if kind == "matmul":
             b, m, n, k = estimator.dot_general_dims(eqn)
             node = MatmulNode(
@@ -231,7 +233,7 @@ def _unrolled_scan(eqn, invals: list, group: int) -> list:
     n_consts, n_carry = int(p["num_consts"]), int(p["num_carry"])
     reverse = bool(p["reverse"])
     body = p["jaxpr"]                       # ClosedJaxpr of the scan body
-    body_fn = jax.core.jaxpr_as_fun(body)
+    body_fn = jaxpr_as_fun(body)
     consts = invals[:n_consts]
     carry = list(invals[n_consts:n_consts + n_carry])
     xs = invals[n_consts + n_carry:]
@@ -278,7 +280,7 @@ def expand_scans(closed_jaxpr, groups: dict[int, int]):
         env: dict = {}
 
         def read(v):
-            return v.val if isinstance(v, jax.core.Literal) else env[v]
+            return v.val if isinstance(v, Literal) else env[v]
 
         for cv, c in zip(jaxpr.constvars, closed_jaxpr.consts):
             env[cv] = c

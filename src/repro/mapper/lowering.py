@@ -74,12 +74,14 @@ import dataclasses
 from typing import Any, Callable
 
 import jax
+from jax.extend.core import Literal
 import jax.numpy as jnp
 
 from repro import obs
 from repro.core import estimator
 from repro.core import quant
 from repro.core.estimator import CALL_PRIMS, inner_jaxpr
+from repro.kernels.mode import resolve_interpret
 from repro.kernels.pim_mac import (pim_mac, pim_mac_grouped, pim_matmul,
                                    pim_matmul_grouped, pim_matmul_grouped_q)
 
@@ -112,7 +114,7 @@ class LoweringContext:
 
     schedule: Any                 # repro.mapper.schedule.Schedule
     block: int = 128              # pallas tile edge (pad-to multiple)
-    interpret: bool = True
+    interpret: bool | None = None   # None: compiled on a TPU only
     group: bool = True            # grouped launches (False = per-block)
     fuse: bool = True             # cross-equation coalescing
     weight_dtype: str | None = None  # default: the schedule's subarray grid
@@ -122,6 +124,7 @@ class LoweringContext:
     eltwise_launches: int = 0
 
     def __post_init__(self):
+        self.interpret = resolve_interpret(self.interpret)
         self.node_by_eqn = {nd.eqn_id: nd
                             for nd in self.schedule.graph.nodes}
         self._subtree_cache: dict[int, bool] = {}
@@ -457,7 +460,7 @@ def _fuse_matmuls(ctx: LoweringContext, lead, peers, env, fused, read,
         if i == 0:
             outs0 = lowered
         else:
-            jax.util.safe_map(env.__setitem__, e2.outvars, lowered)
+            env.update(zip(e2.outvars, lowered, strict=True))
             fused.add(id(e2))
     return outs0
 
@@ -497,7 +500,7 @@ def _fuse_eltwise(ctx: LoweringContext, lead, peers, env, fused, read,
         if i == 0:
             outs0 = lowered
         else:
-            jax.util.safe_map(env.__setitem__, e2.outvars, lowered)
+            env.update(zip(e2.outvars, lowered, strict=True))
             fused.add(id(e2))
     return outs0
 
@@ -540,10 +543,10 @@ def eval_eqns(ctx: LoweringContext, eqns, env: dict) -> None:
     """
 
     def read(v):
-        return v.val if isinstance(v, jax.core.Literal) else env[v]
+        return v.val if isinstance(v, Literal) else env[v]
 
     def ready(e) -> bool:
-        return all(isinstance(v, jax.core.Literal) or v in env
+        return all(isinstance(v, Literal) or v in env
                    for v in e.invars)
 
     # pre-filter fusion candidates per kind once: each lead then scans
@@ -608,7 +611,7 @@ def eval_eqns(ctx: LoweringContext, eqns, env: dict) -> None:
             subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
             ans = eqn.primitive.bind(*subfuns, *invals, **bind_params)
             outs = list(ans) if eqn.primitive.multiple_results else [ans]
-        jax.util.safe_map(env.__setitem__, eqn.outvars, outs)
+        env.update(zip(eqn.outvars, outs, strict=True))
 
 
 def eval_placed(ctx: LoweringContext, jaxpr, consts, args) -> list[Any]:
@@ -618,8 +621,8 @@ def eval_placed(ctx: LoweringContext, jaxpr, consts, args) -> list[Any]:
     (compiler): the only difference is who calls it and when.
     """
     env: dict[Any, Any] = {}
-    jax.util.safe_map(env.__setitem__, jaxpr.constvars, consts)
-    jax.util.safe_map(env.__setitem__, jaxpr.invars, args)
+    env.update(zip(jaxpr.constvars, consts, strict=True))
+    env.update(zip(jaxpr.invars, args, strict=True))
     eval_eqns(ctx, jaxpr.eqns, env)
-    return [v.val if isinstance(v, jax.core.Literal) else env[v]
+    return [v.val if isinstance(v, Literal) else env[v]
             for v in jaxpr.outvars]

@@ -55,6 +55,7 @@ import math
 from typing import Iterator
 
 import jax
+from jax.extend.core import Literal
 import numpy as np
 
 from repro.mapper.graph import OpGraph, OpNode
@@ -184,14 +185,14 @@ def _boundary_cut_bits(jaxpr, n_bits: int) -> list[int]:
     last_read: dict = {}
     for e, eqn in enumerate(eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal) and v in produced:
+            if not isinstance(v, Literal) and v in produced:
                 last_read[v] = e
         for v in eqn.outvars:
             if not isinstance(v, jax.core.DropVar):
                 produced[v] = e
                 last_read[v] = e
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal) and v in produced:
+        if not isinstance(v, Literal) and v in produced:
             last_read[v] = n_eqns          # live past every boundary
     diff = [0] * (n_eqns + 2)
     for v, p in produced.items():

@@ -270,7 +270,7 @@ def pipeline_drift(timeline: Any, tracer: Tracer | None = None,
 
 
 def measure_drift(schedule: Any, *args, group: bool = False,
-                  fuse: bool = False, interpret: bool = True,
+                  fuse: bool = False, interpret: bool | None = None,
                   block: int = 128, **kwargs) -> DriftReport:
     """Run ``schedule`` once through the eager executor under a scoped
     tracer and return the joined :class:`DriftReport`.

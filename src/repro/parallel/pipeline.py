@@ -49,7 +49,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
-from repro.parallel._compat import pcast_varying, shard_map
 
 
 def pipeline_forward(x, stage_params, stage_fn: Callable, *, axis: str,
@@ -90,8 +89,10 @@ def pipeline_forward(x, stage_params, stage_fn: Callable, *, axis: str,
 
     # mark the carries as device-varying along the pipe axis (shard_map
     # vma typing: they hold per-stage values)
-    inflight0 = pcast_varying(jnp.zeros(mb_shape, x.dtype), axis)
-    outputs0 = pcast_varying(jnp.zeros((n_micro,) + mb_shape, x.dtype), axis)
+    inflight0 = jax.lax.pcast(jnp.zeros(mb_shape, x.dtype), (axis,),
+                              to="varying")
+    outputs0 = jax.lax.pcast(jnp.zeros((n_micro,) + mb_shape, x.dtype),
+                             (axis,), to="varying")
     (_, outputs), _ = jax.lax.scan(tick, (inflight0, outputs0),
                                    jnp.arange(n_ticks))
     # broadcast final outputs from the last stage to all stages so the
@@ -357,7 +358,7 @@ def make_pipelined_fn(stage_fn: Callable, mesh: Mesh, *, axis: str = "pod",
         # outputs are broadcast from the last stage via ppermute, so they
         # ARE replicated along the pipe axis — the vma checker cannot
         # prove it statically, hence check_vma=False.
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(axis)),
             out_specs=P(),

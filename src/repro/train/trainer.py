@@ -34,8 +34,10 @@ from repro.train.monitor import HeartbeatMonitor, StragglerPolicy
 @dataclasses.dataclass
 class TrainerConfig:
     total_steps: int
+    # no default: the trainer resumes from whatever this directory holds,
+    # so a shared fallback path would silently continue an unrelated run
+    ckpt_dir: str
     ckpt_every: int = 50
-    ckpt_dir: str = "/tmp/repro_ckpt"
     keep: int = 3
     async_ckpt: bool = True
     log_every: int = 10

@@ -18,6 +18,7 @@ never skipped.
 """
 
 import jax
+from jax.extend.core import jaxpr_as_fun
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -84,7 +85,7 @@ def test_expanded_graph_matches_scanned_totals_and_numerics():
 
     # numerics bit-exact: the expanded jaxpr replays the same primitives
     want = jax.jit(fn)(ws, x)
-    got = jax.core.jaxpr_as_fun(ex.closed_jaxpr)(ws, x)[0]
+    got = jaxpr_as_fun(ex.closed_jaxpr)(ws, x)[0]
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 

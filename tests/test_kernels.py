@@ -8,8 +8,10 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.pim_fp import pim_fp32_mul
+from repro.kernels.pim_mac import pim_mac, pim_matmul
 
 
 @pytest.mark.parametrize("shape", [(64,), (1000,), (7, 130)])
@@ -18,7 +20,7 @@ def test_pim_mac_sweep(rng, shape, block):
     a = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     b = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     acc = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    got = ops.mac(a, b, acc, block=block)
+    got = pim_mac(a, b, acc, block=block)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(ref.pim_mac_ref(a, b, acc)),
                                rtol=1e-6, atol=1e-6)
@@ -31,7 +33,7 @@ def test_pim_matmul_sweep(rng, mnk, dtype):
     m, n, k = mnk
     a = jnp.asarray(rng.standard_normal((m, k)), dtype)
     b = jnp.asarray(rng.standard_normal((k, n)), dtype)
-    got = ops.matmul(a, b)
+    got = pim_matmul(a, b)
     want = ref.pim_matmul_ref(a, b)
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -46,7 +48,7 @@ def test_flash_attention_sweep(rng, bshgd, dtype):
     q = jnp.asarray(rng.standard_normal((b, s, h, d)), dtype)
     k = jnp.asarray(rng.standard_normal((b, s, g, d)), dtype)
     v = jnp.asarray(rng.standard_normal((b, s, g, d)), dtype)
-    got = ops.attention(q, k, v, q_chunk=64, kv_chunk=64)
+    got = flash_attention(q, k, v, q_chunk=64, kv_chunk=64)
     want = ref.flash_attention_ref(q, k, v)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),

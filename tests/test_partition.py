@@ -413,10 +413,10 @@ def test_trainer_microbatch_pipeline_matches_jit(tmp_path):
     assert tr.pim_program.stage_trace_count == traced
 
 
-def test_trainer_knobs_validated():
+def test_trainer_knobs_validated(tmp_path):
     from repro.train import Trainer, TrainerConfig
 
-    tc = TrainerConfig(total_steps=1)
+    tc = TrainerConfig(total_steps=1, ckpt_dir=str(tmp_path))
     with pytest.raises(ValueError, match="backend='pim'"):
         Trainer(tc, train_step=lambda *a: a, init_state=lambda: ({}, {}),
                 batch_fn=lambda s: (), backend="jit", microbatches=4)

@@ -5,10 +5,11 @@ from helpers import run_with_devices
 _PIPE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import auto_mesh
 from repro.parallel.pipeline import make_pipelined_fn
 
 P_STAGES, LAYERS_PER_STAGE, N_MICRO, MB, D = 2, 3, 4, 2, 16
-mesh = jax.make_mesh((P_STAGES,), ("pod",))
+mesh = auto_mesh((P_STAGES,), ("pod",))
 
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (P_STAGES, LAYERS_PER_STAGE, D, D)) * 0.3

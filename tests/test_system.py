@@ -115,12 +115,13 @@ import jax, jax.numpy as jnp, numpy as np, dataclasses
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import auto_mesh
 from repro.parallel import sharding
 from repro.optim import make_optimizer
 
 assert len(jax.devices()) == 8, jax.devices()
 cfg = configs.get_smoke_config("llama3-8b")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = auto_mesh((4, 2), ("data", "model"))
 rules = sharding.single_pod_rules(mesh)
 
 from repro.models.transformer import build_model
@@ -165,18 +166,18 @@ def test_sharded_train_step_matches_single_device():
 _COMPRESSED_PSUM = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.parallel._compat import shard_map
+from repro.launch.mesh import auto_mesh
 from repro.optim import compressed_psum
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = auto_mesh((8,), ("data",))
 g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
 
 def f(g):
     red, err = compressed_psum({"g": g[0]}, "data", None)
     return red["g"][None], err["g"][None]
 
-red, err = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
-                             out_specs=(P("data"), P("data"))))(g_global)
+red, err = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                                 out_specs=(P("data"), P("data"))))(g_global)
 want = jnp.mean(g_global, axis=0)
 got = red[0]
 rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
@@ -195,19 +196,19 @@ def test_compressed_psum_multidevice():
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
+        from repro.launch.mesh import auto_mesh
         from repro.optim import compressed_psum
-        from repro.parallel._compat import shard_map
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = auto_mesh((8,), ("data",))
         g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
 
         def f(g):
             red, err = compressed_psum({"g": g[0]}, "data", None)
             return red["g"][None], err["g"][None]
 
-        red, err = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
-                                     out_specs=(P("data"), P("data"))))(
-                                         g_global)
+        red, err = jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=P("data"),
+            out_specs=(P("data"), P("data"))))(g_global)
         want = jnp.mean(g_global, axis=0)
         rel = float(jnp.abs(red[0] - want).max() / jnp.abs(want).max())
         assert rel < 0.02, rel      # int8 quantization error bound
