@@ -37,6 +37,12 @@ does):
                          peripheral FP units serving a whole wave of
                          eltwise ops per dispatch.
 
+Each ``pallas_call`` names itself after its entry point (``name=``:
+``pim_mac``, ``pim_matmul``, ``pim_matmul_grouped``,
+``pim_matmul_grouped_q``), so the compiled custom call — and the op a
+profile shows — keeps that name whatever the kernel functions or their
+callers are called.
+
 All carry a ``custom_vjp`` whose backward passes are themselves PIM
 kernel calls (dA = g @ B^T and dB = A^T @ g are in-array matmuls — and
 for the grouped forms, *grouped* in-array matmuls, so ``jax.grad``
@@ -99,6 +105,7 @@ def _mac_call(a, b, acc, block: int, interpret: bool) -> jnp.ndarray:
         out_specs=pl.BlockSpec((tr, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, block), acc.dtype),
         interpret=interpret,
+        name="pim_mac",
     )(a2, b2, acc2)
     if aligned:
         return out
@@ -211,6 +218,7 @@ def _matmul_call(a, b, bm: int, bn: int, bk: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="pim_matmul",
     )(a, b)
 
 
@@ -291,6 +299,7 @@ def _matmul_grouped_call(a, b, bm: int, bn: int, bk: int,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="pim_matmul_grouped",
     )(a, b)
 
 
@@ -402,6 +411,7 @@ def _matmul_grouped_q_call(a, q, s, bm: int, bn: int, bk: int,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="pim_matmul_grouped_q",
     )(a, q, s)
 
 

@@ -221,6 +221,19 @@ def compile_schedule(schedule: Schedule, *, block: int = 128,
         _STATS["misses"] += 1
         obs.metrics().counter("compile.cache_misses").inc()
 
+    with obs.span("compile:schedule", lane="compile"), \
+            obs.mapper_phase("compile_schedule"):
+        program = _compile_schedule(schedule, block, interpret, group, fuse)
+    if use_cache:
+        _CACHE[key] = program
+        while len(_CACHE) > _CACHE_MAX:
+            _CACHE.popitem(last=False)
+    return program
+
+
+def _compile_schedule(schedule: Schedule, block: int, interpret: bool,
+                      group: bool, fuse: bool) -> CompiledProgram:
+    """The program object itself; its jit traces on the first call."""
     ctx = LoweringContext(schedule, block=block, interpret=interpret,
                           group=group, fuse=fuse)
     closed = schedule.graph.closed_jaxpr
@@ -256,10 +269,6 @@ def compile_schedule(schedule: Schedule, *, block: int = 128,
     program = CompiledProgram(schedule=schedule, fn=fn, jitted=jax.jit(fn),
                               ctx=ctx)
     holder.append(program)
-    if use_cache:
-        _CACHE[key] = program
-        while len(_CACHE) > _CACHE_MAX:
-            _CACHE.popitem(last=False)
     return program
 
 

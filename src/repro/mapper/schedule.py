@@ -559,7 +559,8 @@ def build_schedule(fn: Callable, *args,
             f"hierarchy's subarray ({hierarchy.subarray.weight_dtype!r}); "
             f"build the hierarchy with default_hierarchy(tech, "
             f"weight_dtype) instead")
-    with obs.span("build:schedule", lane="compile"):
+    with obs.span("build:schedule", lane="compile"), \
+            obs.mapper_phase("build_schedule"):
         g = graph_mod.build_graph(fn, *args, **kwargs)
         sched = build_schedule_from_graph(g, hierarchy=hierarchy,
                                           policy=policy, tech=tech,

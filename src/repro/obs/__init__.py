@@ -15,13 +15,29 @@ Three layers, one switch:
     against the schedule's *modeled* stage costs and reports per-node
     modeled-vs-measured ratios.
 
-Cost discipline: tracing is **opt-in** (:func:`enable`) and the stack's
-hot paths guard on ``tracer().enabled`` — when disabled the only cost is
-an attribute check, no span args are built, no device syncs happen, and
-no jit retraces are introduced (instrumentation wraps ``pallas_call``
-dispatch sites and program boundaries, never traced code). The metrics
-registry is always-on but only touched at program boundaries (per step /
-tick / request / compile), where a dict update is noise.
+Two more records ride on the same layer:
+
+  * **the profiler clock** — while a JAX profiler session is recording,
+    every span also opens a ``jax.profiler.TraceAnnotation`` of its name
+    with its args as metadata (``repro.obs.trace``), so the program's
+    own spans land in the profile beside the device's ops;
+  * **the compile record** (``repro.obs.compile_record``) — always on: one
+    entry per program built (label, key, trace / lower / backend-compile
+    / cache-read seconds, cache hit, first call's wall time, recompile)
+    and per mapper phase, fed by one ``jax.monitoring`` listener.
+
+Cost discipline: tracing is **opt-in** (:func:`enable`). When neither
+the tracer nor a profiler is on, a span costs one check
+(:func:`recording`): no span args are built (hot paths pass them
+lazily, ``span(..., lazy=...)``), no ``TraceAnnotation`` is made, no
+device syncs happen, and no jit retraces are introduced
+(instrumentation wraps ``pallas_call`` dispatch sites and program
+boundaries, never traced code, and no ``named_scope`` is put into traced
+code). The syncs some spans make (``program:call``, the per-node
+``execute`` spans) happen only with the tracer enabled, never for the
+profiler alone. The metrics registry and the compile record are
+always-on but only touched at program boundaries (per step / tick /
+request / compile), where a dict update is noise.
 
 Usage::
 
@@ -50,8 +66,10 @@ from repro.obs.drift import (DriftReport, NodeDrift, PipelineDrift,
                              pipeline_drift)
 from repro.obs.metrics import (DEFAULT_EDGES, Counter, Gauge, Histogram,
                                MetricsRegistry)
+from repro.obs.compile_record import (Build, compiles, mapper_phase, program,
+                                reset_compiles)
 from repro.obs.trace import (NULL_TRACER, NullTracer, SpanEvent, Tracer,
-                             validate_chrome_trace)
+                             profiler_recording, validate_chrome_trace)
 
 _TRACER: Tracer | NullTracer = NULL_TRACER
 _METRICS = MetricsRegistry()
@@ -69,6 +87,12 @@ def metrics() -> MetricsRegistry:
 
 def is_enabled() -> bool:
     return _TRACER.enabled
+
+
+def recording() -> bool:
+    """Whether a span made now would be recorded anywhere: the tracer is
+    enabled or a profiler session is recording."""
+    return _TRACER.enabled or profiler_recording()
 
 
 def enable(tracer: Tracer | None = None) -> Tracer:
@@ -98,11 +122,20 @@ def scoped(tracer: Tracer | None = None):
         _TRACER = prev
 
 
-def span(name: str, lane: str = "main", **args):
-    """Module-level convenience: a span on the installed tracer (no-op
-    context when disabled). Hot paths should guard on
-    ``tracer().enabled`` instead, to skip building ``args``."""
-    return _TRACER.span(name, lane=lane, **args)
+_NULL_CM = NullTracer._NULL_CM
+
+
+def span(name: str, lane: str = "main", lazy=None, **args):
+    """A span on the installed tracer, and on the profiler clock while a
+    profiler is recording; the shared no-op context when neither is on.
+    ``lazy``, a function returning a dict, gives args that are built
+    only when the span is recorded."""
+    tr = _TRACER
+    if not (tr.enabled or profiler_recording()):
+        return _NULL_CM
+    if lazy is not None:
+        args.update(lazy())
+    return tr.span(name, lane=lane, **args)
 
 
 def instant(name: str, lane: str = "main", **args) -> None:
@@ -110,10 +143,11 @@ def instant(name: str, lane: str = "main", **args) -> None:
 
 
 __all__ = [
-    "Counter", "DEFAULT_EDGES", "DriftReport", "Gauge", "Histogram",
-    "MetricsRegistry", "NULL_TRACER", "NodeDrift", "NullTracer",
-    "PipelineDrift", "SpanEvent", "StageOccupancy", "Tracer", "disable",
-    "drift_report", "enable", "instant", "is_enabled", "measure_drift",
-    "metrics", "pipeline_drift", "scoped", "span", "tracer",
-    "validate_chrome_trace",
+    "Build", "Counter", "DEFAULT_EDGES", "DriftReport", "Gauge",
+    "Histogram", "MetricsRegistry", "NULL_TRACER", "NodeDrift",
+    "NullTracer", "PipelineDrift", "SpanEvent", "StageOccupancy", "Tracer",
+    "compiles", "disable", "drift_report", "enable", "instant",
+    "is_enabled", "mapper_phase", "measure_drift", "metrics",
+    "pipeline_drift", "profiler_recording", "program", "recording",
+    "reset_compiles", "scoped", "span", "tracer", "validate_chrome_trace",
 ]

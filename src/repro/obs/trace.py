@@ -16,8 +16,17 @@ which the observability tests run on every exported file).
 The :class:`NullTracer` is the disabled mode: ``enabled`` is False and
 ``span()`` hands back one shared no-op context manager, so instrumented
 call sites cost an attribute check when observability is off. Call sites
-on hot paths additionally guard with ``if tracer.enabled`` so even the
+on hot paths pass their span arguments lazily (``repro.obs.span(...,
+lazy=...)``) or guard with ``if tracer.enabled`` so even the
 span-argument dicts are never built.
+
+**The profiler clock.** While a JAX profiler session is recording
+(:func:`profiler_recording`, one ``TraceMe.is_enabled`` call), every span
+— of a :class:`Tracer` or of the :class:`NullTracer` — also opens a
+``jax.profiler.TraceAnnotation`` of the same name with the span's args as
+metadata, so the program's spans land in the ``.xplane.pb`` beside the
+device's ops, on the device's clock. Nothing of this syncs: a span on the
+profiler clock is paired with the device work it launched by time.
 
 Durations are wall-clock (``time.perf_counter``). Callers that time JAX
 dispatch sites must ``jax.block_until_ready`` *inside* the span —
@@ -33,6 +42,15 @@ import dataclasses
 import json
 import time
 from typing import Any, Callable, Iterable
+
+from jax.profiler import TraceAnnotation
+
+
+def profiler_recording() -> bool:
+    """Whether a JAX profiler session is recording right now: the one
+    check every span makes before it touches the profiler."""
+    return TraceAnnotation.is_enabled()
+
 
 
 @dataclasses.dataclass
@@ -76,11 +94,16 @@ class Tracer:
         """Context manager recording one timed interval on ``lane``."""
         depth = self._depth.get(lane, 0)
         self._depth[lane] = depth + 1
+        ann = TraceAnnotation(name, **args) if profiler_recording() else None
+        if ann is not None:
+            ann.__enter__()
         t0 = self._clock()
         try:
             yield self
         finally:
             dur = self._clock() - t0
+            if ann is not None:
+                ann.__exit__(None, None, None)
             self._depth[lane] = depth
             self.events.append(SpanEvent(
                 name=name, lane=lane, t0_s=t0 - self._epoch, dur_s=dur,
@@ -142,7 +165,8 @@ class Tracer:
 
 class NullTracer:
     """Disabled tracer: every operation is a no-op; ``enabled`` is False
-    so hot paths can skip building span arguments entirely."""
+    so hot paths can skip building span arguments entirely. A span still
+    reaches the profiler clock while a profiler session is recording."""
 
     enabled = False
     events: tuple = ()
@@ -153,6 +177,8 @@ class NullTracer:
         return 0
 
     def span(self, name: str = "", lane: str = "main", **args):
+        if profiler_recording():
+            return TraceAnnotation(name, **args)
         return self._NULL_CM
 
     def instant(self, name: str = "", lane: str = "main", **args) -> None:

@@ -378,11 +378,13 @@ class ServeEngine:
                 token_bits=kv_mod.kv_token_bits(
                     self.cfg.n_kv_heads, self.cfg.resolved_head_dim,
                     self.kv_dtype))
-            self.kv_placement = mapper.place_kv(sched.graph,
-                                                sched.placement, spec)
-            sched.attach_kv(self.kv_placement,
-                            resident_tokens=max(1, self.max_len // 2),
-                            batch=self.batch)
+            with obs.span("place:kv", lane="compile"), \
+                    obs.mapper_phase("place_kv"):
+                self.kv_placement = mapper.place_kv(sched.graph,
+                                                    sched.placement, spec)
+                sched.attach_kv(self.kv_placement,
+                                resident_tokens=max(1, self.max_len // 2),
+                                batch=self.batch)
         self.schedule = sched
         # use_cache=False: the cache keys on fn identity and this is
         # a bound method — per-engine keys would never hit but would
@@ -504,28 +506,27 @@ class ServeEngine:
                         and not self._admissible(req)):
                     break   # FIFO: the head waits, nothing overtakes it
                 self.queue.popleft()
-                self.slots[s] = req
-                self._adm_seq[s] = self._adm_counter
-                self._adm_counter += 1
-                obs.metrics().counter("serve.admitted").inc()
-                tr = obs.tracer()
-                if tr.enabled:
-                    tr.instant("admit", lane="serve", rid=req.rid, slot=s)
-                # explicit per-slot state reset on (re)admission — a
-                # recycled slot must never rely on the prompt phase
-                # masking the previous occupant's sample/cursor
-                self._prompt_idx[s] = 0
-                self._last_tok[s] = 0
-                if self.paged and req.resume is not None:
-                    self._resume_slot(s, req)
-                elif self.paged:
-                    shared = self.kv.alloc_slot(s, req.prompt)
-                    self._pos[s] = shared
-                    self._prompt_idx[s] = shared   # skip cached prefix
-                    self.prefix_skipped_tokens += shared
-                    self._work -= shared
-                    if self.prefill == "batch":
-                        self._prefill_slot(s, req, shared)
+                with obs.span("admit", lane="serve",
+                              lazy=lambda: {"rid": req.rid, "slot": s}):
+                    self.slots[s] = req
+                    self._adm_seq[s] = self._adm_counter
+                    self._adm_counter += 1
+                    obs.metrics().counter("serve.admitted").inc()
+                    # explicit per-slot state reset on (re)admission — a
+                    # recycled slot must never rely on the prompt phase
+                    # masking the previous occupant's sample/cursor
+                    self._prompt_idx[s] = 0
+                    self._last_tok[s] = 0
+                    if self.paged and req.resume is not None:
+                        self._resume_slot(s, req)
+                    elif self.paged:
+                        shared = self.kv.alloc_slot(s, req.prompt)
+                        self._pos[s] = shared
+                        self._prompt_idx[s] = shared   # skip cached prefix
+                        self.prefix_skipped_tokens += shared
+                        self._work -= shared
+                        if self.prefill == "batch":
+                            self._prefill_slot(s, req, shared)
 
     def _resume_slot(self, s: int, req: Request) -> None:
         """Re-admit a preempted request: migrate its scratch pages back
@@ -607,24 +608,27 @@ class ServeEngine:
         n_new = len(req.prompt) - 1 - p0
         if n_new < 1:
             return
-        with obs.span("prefill:batch", lane="serve", rid=req.rid, slot=s,
-                      tokens=n_new):
-            self._prefill_slot_inner(s, req, p0, n_new)
+        bs = self.block_size
+        t_pad = -(-n_new // bs) * bs            # bucket: bounded retraces
+        with obs.span("prefill:batch", lane="serve",
+                      lazy=lambda: {"rid": req.rid, "slot": s,
+                                    "tokens": n_new, "bucket": t_pad}):
+            self._prefill_slot_inner(s, req, p0, n_new, t_pad)
         obs.metrics().counter("serve.prefill_tokens").inc(n_new)
 
     def _prefill_slot_inner(self, s: int, req: Request, p0: int,
-                            n_new: int) -> None:
+                            n_new: int, t_pad: int) -> None:
         bs = self.block_size
         # p0 is block-aligned (admission attaches whole cached blocks),
         # so one ensure/note_filled per covered block suffices
         for pos in range(p0, p0 + n_new, bs):   # allocate covering blocks
             self.cache = self.kv.ensure(self.cache, s, pos)
-        t_pad = -(-n_new // bs) * bs            # bucket: bounded retraces
         toks = np.zeros(t_pad, np.int32)
         toks[:n_new] = req.prompt[p0:p0 + n_new]
-        self.cache = self._prefill_fn(
-            self.params, self.cache, jnp.asarray(toks),
-            self.kv.device_table()[s], jnp.int32(p0), jnp.int32(n_new))
+        args = (self.params, self.cache, jnp.asarray(toks),
+                self.kv.device_table()[s], jnp.int32(p0), jnp.int32(n_new))
+        with obs.program("serve.prefill", t_pad):
+            self.cache = self._prefill_fn(*args)
         for pos in range(p0 + bs - 1, p0 + n_new, bs):
             self.kv.note_filled(s, pos)         # register full prompt blocks
         self._pos[s] = p0 + n_new
@@ -652,10 +656,12 @@ class ServeEngine:
     def step(self, tick: int, tokens: np.ndarray) -> np.ndarray:
         """Advance every slot one token (contiguous path); returns next
         tokens [B]."""
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          jnp.asarray(tokens),
-                                          jnp.int32(tick))
-        return np.asarray(self.sample(logits), np.int32)
+        args = (self.params, self.cache, jnp.asarray(tokens),
+                jnp.int32(tick))
+        with obs.program("serve.decode"):
+            logits, self.cache = self._decode(*args)
+        with obs.span("sample:sync", lane="serve"):
+            return np.asarray(self.sample(logits), np.int32)
 
     def tick_once(self) -> bool:
         """Advance every active slot one token. Any slot that finishes is
@@ -663,81 +669,98 @@ class ServeEngine:
         batching — see the trailing ``_admit``). Returns False when no
         progress is possible: nothing admitted, or — contiguous only —
         the shared tick reached the lane bound (capacity exhaustion)."""
-        self._admit()
-        active = [s for s in range(self.batch) if self.slots[s] is not None]
-        if not active:
-            return False
-        if not self.paged and self._tick >= self.max_len - 1:
-            return False          # shared lanes full; caller reports starved
-        if self.paged:
-            # writability first: this may preempt (swap out) victims, so
-            # the feed is built only from the survivors
-            active = self._ensure_active(active)
-        feed = np.zeros(self.batch, np.int32)
-        for s in active:
-            req = self.slots[s]
-            k = int(self._prompt_idx[s])
-            feed[s] = (req.prompt[k] if k < len(req.prompt)
-                       else self._last_tok[s])
-        if self.paged:
-            with obs.span("decode:tick", lane="serve", tick=self._tick,
-                          active=len(active)):
-                logits, self.cache = self._decode(
-                    self.params, self.cache, jnp.asarray(feed),
-                    self.kv.device_table(), jnp.asarray(self._pos))
-                nxt = np.asarray(self.sample(logits), np.int32)
-            bs = self.block_size
+        with obs.span("tick", lane="serve",
+                      lazy=lambda: {"tick": self._tick}):
+            self._admit()
+            active = [s for s in range(self.batch)
+                      if self.slots[s] is not None]
+            if not active:
+                return False
+            if not self.paged and self._tick >= self.max_len - 1:
+                return False      # shared lanes full; caller reports starved
+            if self.paged:
+                # writability first: this may preempt (swap out) victims, so
+                # the feed is built only from the survivors
+                active = self._ensure_active(active)
+            feed = np.zeros(self.batch, np.int32)
             for s in active:
-                self.kv.note_filled(s, int(self._pos[s]))
-                self._pos[s] += 1
-                # block-granular read + one-token write per site
-                self.kv_bytes_read += (math.ceil(int(self._pos[s]) / bs)
-                                       * bs * self._tok_bytes)
-            self.kv_bytes_written += len(active) * self._tok_bytes
-        else:
-            with obs.span("decode:tick", lane="serve", tick=self._tick,
-                          active=len(active)):
-                nxt = self.step(self._tick, feed)
-            # contiguous lanes stream their full provisioned length
-            self.kv_bytes_read += len(active) * self.max_len \
-                * self._tok_bytes
-            self.kv_bytes_written += len(active) * self._tok_bytes
-        for s in active:
-            req = self.slots[s]
-            self._work -= 1        # one prompt or output token per tick
-            if self._prompt_idx[s] < len(req.prompt) - 1:
-                self._prompt_idx[s] += 1
+                req = self.slots[s]
+                k = int(self._prompt_idx[s])
+                feed[s] = (req.prompt[k] if k < len(req.prompt)
+                           else self._last_tok[s])
+            if self.paged:
+                with obs.span("decode:tick", lane="serve",
+                              lazy=lambda: self._decode_args(active)):
+                    args = (self.params, self.cache, jnp.asarray(feed),
+                            self.kv.device_table(), jnp.asarray(self._pos))
+                    with obs.program("serve.decode"):
+                        logits, self.cache = self._decode(*args)
+                    with obs.span("sample:sync", lane="serve"):
+                        nxt = np.asarray(self.sample(logits), np.int32)
+                bs = self.block_size
+                for s in active:
+                    self.kv.note_filled(s, int(self._pos[s]))
+                    self._pos[s] += 1
+                    # block-granular read + one-token write per site
+                    self.kv_bytes_read += (math.ceil(int(self._pos[s]) / bs)
+                                           * bs * self._tok_bytes)
+                self.kv_bytes_written += len(active) * self._tok_bytes
             else:
-                self._prompt_idx[s] = len(req.prompt)  # gen: feed samples
-                req.out.append(int(nxt[s]))
-                self._last_tok[s] = nxt[s]
-                if req.t_first is None:
-                    req.t_first = time.monotonic()
-                    if req.t_submit is not None:
-                        obs.metrics().histogram("serve.ttft_s").observe(
-                            req.t_first - req.t_submit)
-                hit_eos = req.eos is not None and int(nxt[s]) == req.eos
-                if len(req.out) >= req.max_tokens or hit_eos:
-                    req.done = True
-                    self._work -= req.max_tokens - len(req.out)  # early EOS
-                    req.t_done = time.monotonic()
-                    if req.tpot_s is not None:
-                        obs.metrics().histogram("serve.tpot_s").observe(
-                            req.tpot_s)
-                    obs.metrics().counter("serve.completed").inc()
-                    self.completed.append(req)
-                    self._recycle(s)
-        self._admit()
-        self._tick += 1
-        m = obs.metrics()
-        m.counter("serve.ticks").inc()
-        m.gauge("serve.queue_depth").set(len(self.queue))
+                with obs.span("decode:tick", lane="serve",
+                              lazy=lambda: self._decode_args(active)):
+                    nxt = self.step(self._tick, feed)
+                # contiguous lanes stream their full provisioned length
+                self.kv_bytes_read += len(active) * self.max_len \
+                    * self._tok_bytes
+                self.kv_bytes_written += len(active) * self._tok_bytes
+            for s in active:
+                req = self.slots[s]
+                self._work -= 1        # one prompt or output token per tick
+                if self._prompt_idx[s] < len(req.prompt) - 1:
+                    self._prompt_idx[s] += 1
+                else:
+                    # gen: feed samples
+                    self._prompt_idx[s] = len(req.prompt)
+                    req.out.append(int(nxt[s]))
+                    self._last_tok[s] = nxt[s]
+                    if req.t_first is None:
+                        req.t_first = time.monotonic()
+                        if req.t_submit is not None:
+                            obs.metrics().histogram("serve.ttft_s").observe(
+                                req.t_first - req.t_submit)
+                    hit_eos = req.eos is not None and int(nxt[s]) == req.eos
+                    if len(req.out) >= req.max_tokens or hit_eos:
+                        req.done = True
+                        # early EOS
+                        self._work -= req.max_tokens - len(req.out)
+                        req.t_done = time.monotonic()
+                        if req.tpot_s is not None:
+                            obs.metrics().histogram("serve.tpot_s").observe(
+                                req.tpot_s)
+                        obs.metrics().counter("serve.completed").inc()
+                        self.completed.append(req)
+                        self._recycle(s)
+            self._admit()
+            self._tick += 1
+            m = obs.metrics()
+            m.counter("serve.ticks").inc()
+            m.gauge("serve.queue_depth").set(len(self.queue))
+            if self.paged:
+                m.gauge("serve.kv_live_blocks").set(self.kv.live_blocks)
+                m.gauge("serve.kv_cached_blocks").set(self.kv.cached_blocks)
+                m.gauge("serve.kv_free_blocks").set(self.kv.free_blocks)
+                m.gauge("serve.kv_swapped_blocks").set(self.swapped_blocks)
+            return True
+
+    def _decode_args(self, active: list[int]) -> dict:
+        """The ``decode:tick`` span's args: the tick, the slots decoded
+        and the keys they attend over (their cached lengths plus the new
+        token)."""
         if self.paged:
-            m.gauge("serve.kv_live_blocks").set(self.kv.live_blocks)
-            m.gauge("serve.kv_cached_blocks").set(self.kv.cached_blocks)
-            m.gauge("serve.kv_free_blocks").set(self.kv.free_blocks)
-            m.gauge("serve.kv_swapped_blocks").set(self.swapped_blocks)
-        return True
+            keys = int(self._pos[active].sum()) + len(active)
+        else:
+            keys = len(active) * (self._tick + 1)
+        return {"tick": self._tick, "active": len(active), "keys": keys}
 
     def run(self, max_ticks: int | None = None, *,
             on_starvation: str = "raise") -> list[Request]:

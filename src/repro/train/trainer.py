@@ -237,26 +237,33 @@ class Trainer:
         while step < cfg.total_steps:
             if cfg.fail_at_step is not None and step == cfg.fail_at_step:
                 raise RuntimeError(f"injected node failure at step {step}")
-            t0 = time.monotonic()
-            with obs.span("train:step", lane="train", step=step):
-                batch = self.batch_fn(step)
-                self.params, self.opt_state, loss = self._step_fn(
-                    self.params, self.opt_state, batch)
-                loss = float(loss)    # device sync: dt is true step time
-            dt = time.monotonic() - t0
-            m.histogram("train.step_wall_s").observe(dt)
-            if first_step:
-                # the resumed-run first step pays trace + compile; record
-                # it apart so the steady-state histogram stays clean
-                m.gauge("train.first_step_wall_s").set(dt)
-                first_step = False
-            m.counter("train.steps").inc()
-            self.heartbeat.beat("host0")
-            self.straggler.observe(step, dt)
-            self.losses.append(loss)
-            if step % cfg.ckpt_every == 0 and step > self.start_step:
-                self.ckpt.save(step, {"params": self.params,
-                                      "opt": self.opt_state})
+            with obs.span("train:step", lane="train",
+                          lazy=lambda: {"step": step}):
+                t0 = time.monotonic()
+                with obs.span("train:batch", lane="train"):
+                    batch = self.batch_fn(step)
+                with obs.span("train:dispatch", lane="train"), \
+                        obs.program("train.step"):
+                    self.params, self.opt_state, loss = self._step_fn(
+                        self.params, self.opt_state, batch)
+                with obs.span("train:sync", lane="train"):
+                    loss = float(loss)  # device sync: dt is true step time
+                dt = time.monotonic() - t0
+                m.histogram("train.step_wall_s").observe(dt)
+                if first_step:
+                    # the resumed-run first step pays trace + compile;
+                    # record it apart so the steady-state histogram
+                    # stays clean
+                    m.gauge("train.first_step_wall_s").set(dt)
+                    first_step = False
+                m.counter("train.steps").inc()
+                self.heartbeat.beat("host0")
+                self.straggler.observe(step, dt)
+                self.losses.append(loss)
+                if step % cfg.ckpt_every == 0 and step > self.start_step:
+                    with obs.span("train:ckpt", lane="train"):
+                        self.ckpt.save(step, {"params": self.params,
+                                              "opt": self.opt_state})
             step += 1
         # final checkpoint
         self.ckpt.save(cfg.total_steps - 1,

@@ -204,9 +204,9 @@ def test_paged_serve_trace_drift_and_latency_histograms(tmp_path, llama):
     lanes = obs.validate_chrome_trace(trace_path)
     assert "serve" in lanes and "execute" in lanes
     assert len(tr.spans(lane="serve", name="decode:tick")) > 0
-    admits = [e for e in tr.events if e.kind == "instant"
-              and e.name == "admit"]
+    admits = tr.spans(lane="serve", name="admit")
     assert len(admits) == 3
+    assert sorted(e.args["rid"] for e in admits) == [0, 1, 2]
 
     # the engine's drift report joins the program:call spans against the
     # pim schedule's modeled decode cost

@@ -10,6 +10,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from repro.configs.lenet5 import CONFIG as LENET
 from repro.kernels import (paged_decode_attention_grouped,
                            paged_decode_attention_grouped_q, pim_mac,
                            pim_matmul_grouped)
+from repro.kernels.pim_mac import pim_mac_grouped, pim_matmul_grouped_q
 from repro.models import lenet
 
 # qwen2.5-32b: d_model 5120, d_ff 27648, 40 query / 8 KV heads of 128
@@ -126,3 +128,41 @@ def test_paged_decode_attention_q_compiles_at_qwen_heads(one_chip):
     text = _compile_text(attend, o["q"], o["pool"], o["scale"], o["pool"],
                          o["scale"], o["table"], o["pos"])
     assert "tpu_custom_call" in text
+
+
+_CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%([A-Za-z_][\w\-]*?)(?:\.\d+)? = "
+                          r".*custom_call_target=\"tpu_custom_call\"", re.M)
+
+
+def _kernel_names(text: str) -> list[str]:
+    """The instruction names of a compiled program's Pallas calls (what a
+    profile shows as their op names), without the numeric suffix."""
+    return _CUSTOM_CALL.findall(text)
+
+
+def test_pim_kernels_keep_their_names_forward_and_backward(one_chip):
+    """A profile's op names for the placed kernels are fixed by the
+    kernels themselves, not by the function that calls them or by the
+    transformation (grad) that produced them: the benchmark's roofline
+    readers find them as ``pim_matmul*``."""
+    a = _shape(one_chip, (2, 128, 256))
+    b = _shape(one_chip, (2, 256, 128))
+
+    def fn(a, b):
+        return pim_matmul_grouped(a, b, interpret=False)
+
+    assert _kernel_names(_compile_text(fn, a, b)) == ["pim_matmul_grouped"]
+    grad = jax.grad(lambda a, b: jnp.sum(fn(a, b)), argnums=(0, 1))
+    assert _kernel_names(_compile_text(grad, a, b)) == \
+        ["pim_matmul_grouped"] * 2
+
+    q = _shape(one_chip, (2, 256, 128))           # f32-carried codes
+    sc = _shape(one_chip, (2, 1, 128))
+    assert _kernel_names(_compile_text(
+        lambda a, q, s: pim_matmul_grouped_q(a, q, s, interpret=False),
+        a, q, sc)) == ["pim_matmul_grouped_q"]
+
+    x = _shape(one_chip, (300,))
+    wave = _compile_text(lambda x, y, z: pim_mac_grouped(
+        [(x, y, z), (y, z, x)], interpret=False), x, x, x)
+    assert _kernel_names(wave) == ["pim_mac"]
