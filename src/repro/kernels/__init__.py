@@ -11,10 +11,12 @@
   * ``flash_attention``          — causal GQA attention, online softmax in
                                    VMEM scratch (never writes S x S to HBM)
   * ``paged_decode_attention_grouped`` — paged-KV decode attention for all
-                                   batch slots in one launch, gathering KV
-                                   blocks through a scalar-prefetched block
-                                   table (``..._q``: same launch over a
-                                   quantized pool, dequantize-on-load)
+                                   batch slots and KV heads in one launch,
+                                   copying each slot's pages (and none past
+                                   its position) through a scalar-prefetched
+                                   block table (``..._q``: one KV head and
+                                   block a step over a quantized pool,
+                                   dequantize-on-load)
 
 Each kernel picks its mode itself (``repro.kernels.mode``): compiled on a
 TPU, interpreted elsewhere. ``ref`` holds the pure-jnp oracles.

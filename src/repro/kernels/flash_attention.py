@@ -17,12 +17,15 @@ mathematically identical XLA fallback used on non-TPU backends.
 
 ``paged_decode_attention_grouped`` extends the same grouped-launch idea
 to paged-KV decode serving: one ``pallas_call`` covers *every* batch
-slot, gathering each slot's KV blocks straight out of the shared block
-pool through a scalar-prefetched block table (the index map reads
-``table[b, w]``, so blocks stream in table order with no materialized
-[B, W*bs, G, D] gather) and carrying the online-softmax state in VMEM
-scratch across the block axis. Multi-slot decode thus pays one dispatch
-per tick, not one gather chain per slot/site.
+slot and every KV head, and streams each slot's KV pages straight out of
+the shared block pool with hand-issued async copies addressed through a
+scalar-prefetched block table (no materialized [B, W*bs, G, D] gather).
+A page is one contiguous slab of all KV heads of ``bs`` keys, read as
+``(bs * G, D)``, so one step copies ~128 keys' worth of pages, all heads
+at once, and folds them into every head's online softmax in VMEM scratch
+while the next chunk's copies are already in flight. Copies stop at each
+slot's length, so the work tracks the keys actually cached, not the
+table's width.
 """
 
 from __future__ import annotations
@@ -130,30 +133,77 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, bs: int, n_w: int,
-                         scale: float):
+def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, slot_ref, acc_ref, m_ref, l_ref,
+                         *, bs: int, w: int, g: int, scale: float):
     b = pl.program_id(0)
-    w = pl.program_id(2)
+    n_b = pl.num_programs(0)
+    pages = k_buf.shape[1]
+    h = q_ref.shape[1]
 
-    @pl.when(w == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def n_pages(s):
+        # the pages that hold slot s's keys 0..pos; an idle lane, at
+        # position 0, holds one
+        return jnp.minimum(pos_ref[s] // bs + 1, w)
 
+    def n_chunks(s):
+        return (n_pages(s) + pages - 1) // pages
+
+    def page_copies(s, c, slot, act):
+        """Chunk ``c`` of slot ``s``: one copy of K and one of V for each
+        of its pages that holds a key (``act`` starts or waits on each)."""
+        n = n_pages(s)
+        for j in range(pages):
+            page = c * pages + j
+
+            @pl.when(page < n)
+            def _():
+                blk = tbl_ref[s * w + page]
+                for i, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    act(pltpu.make_async_copy(hbm.at[blk], buf.at[slot, j],
+                                              sems.at[i, slot]))
+
+    @pl.when(b == 0)
+    def _():
+        # pages past a slot's length are never copied: start V from
+        # zeros, so that the value pass multiplies its zero weights by no
+        # uninitialised (non-finite) rows (their scores are masked)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        page_copies(b, 0, 0, lambda cp: cp.start())
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
     p = pos_ref[b]
+    q = q_ref[0]                                   # [H, D]
+    rows = pages * bs * g                          # (key, kv head) rows
+    # a chunk's rows are its keys' kv heads in pool order (key-major);
+    # query head i reads kv head i // rep, and only its rows count
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1)
+    own = (col % g) == jax.lax.broadcasted_iota(jnp.int32, (h, rows),
+                                                 0) // (h // g)
 
-    # skip blocks entirely past the slot's position (their table entries
-    # clamp to the scratch block — garbage that must not join the max)
-    @pl.when(w * bs <= p)
-    def _compute():
-        q = q_ref[0, 0]                    # [R, D]
-        k = k_ref[0]                       # [bs, D]: this kv head's lanes
-        v = v_ref[0]
-        sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        k_pos = w * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(k_pos <= p, sc, NEG_INF)
+    def chunk(c, _):
+        slot = slot_ref[0]
+        last = c + 1 >= n_chunks(b)
+
+        # the next chunk (this slot's, else the next slot's first) streams
+        # into the other buffer while this one is folded in
+        @pl.when(jnp.logical_or(~last, b + 1 < n_b))
+        def _():
+            page_copies(jnp.where(last, b + 1, b), jnp.where(last, 0, c + 1),
+                        1 - slot, lambda cp: cp.start())
+
+        page_copies(b, c, slot, lambda cp: cp.wait())
+        slot_ref[0] = 1 - slot
+        k = k_buf[slot].reshape(rows, -1)          # [keys * G, D]
+        v = v_buf[slot].reshape(rows, -1)
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        key = c * pages * bs + col // g
+        sc = jnp.where(own & (key <= p), sc, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
         pr = jnp.exp(sc - m_new)
@@ -164,10 +214,9 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                                   preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
-    @pl.when(w == n_w - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-20)).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_chunks(b), chunk, None)
+    o_ref[0] = (acc_ref[...]
+                / jnp.maximum(l_ref[...], 1e-20)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -185,55 +234,62 @@ def paged_decode_attention_grouped(q: jnp.ndarray, k_store: jnp.ndarray,
     scratch block); pos: [B] int32 per-slot positions. Returns
     [B, H, D].
 
-    Grid (B, G, W): the slot/kv-head axes are the group dimensions, the
-    block axis is innermost-sequential so the online-softmax scratch
-    (acc, m, l) carries across a slot's blocks. KV blocks are fetched via
-    scalar-prefetch — the k/v index map reads ``block_table[b, w]`` — so
-    the gather happens in the kernel's block streaming, not as a
-    per-slot XLA gather chain.
+    Grid (B,), sequential: one step per slot, with an in-kernel loop over
+    the slot's own chunks of ``P`` = 128 // bs pages (clipped to W), so a
+    slot at position p costs ceil((p + 1) / bs / P) chunks, whatever W.
+    The pools stay in HBM (``memory_space=ANY``); each chunk's pages are
+    copied into VMEM by hand, addressed through the scalar-prefetched
+    table, one async copy of K and one of V per page that holds a key and
+    none past the slot's position, double-buffered: the next chunk (the
+    slot's own, else the next slot's first) is in flight while this one
+    is folded in. A trailing partial chunk copies only its pages and the
+    position mask drops the rest.
 
-    The pool is viewed as ``[N, bs, G * D]`` (a free reshape), so one kv
-    head's share of a block is a ``(bs, D)`` tile on a 128-lane boundary:
-    the block shape the TPU lowering accepts, with the same bytes read.
+    A page is read in the pool's own layout: ``[N, bs, G, D]`` viewed as
+    ``[N, bs * G, D]`` (a bitcast when G is a multiple of the 8-row tile,
+    so no relayout of the pool), one contiguous slab of every KV head of
+    ``bs`` keys. All heads are attended at once: the scores of all H
+    query heads against the chunk's ``keys * G`` (key, kv head) rows are
+    one ``[H, keys * G]`` matmul, and a row of query head i keeps only
+    the columns of kv head i // (H / G), so every kept score is the same
+    f32 dot product as per head and every dropped one weighs exactly zero
+    in the f32 online softmax and the bf16 value pass (one more matmul
+    with the chunk's V rows). The online-softmax state (acc [H, D], m and
+    l [H, 1]) lives in VMEM scratch.
     """
     b, h, d = q.shape
     n_blocks, bs, g, _ = k_store.shape
     w = block_table.shape[1]
-    rep = h // g
-    scale = 1.0 / math.sqrt(d)
-    qg = q.reshape(b, g, rep, d)
-    kv_spec = pl.BlockSpec((1, bs, d), lambda ib, ig, iw, tbl, pos:
-                           (tbl[ib, iw], 0, ig))
+    pages = max(1, min(w, 128 // bs))     # ~128 keys a chunk, <= the table
+    row_spec = pl.BlockSpec((1, h, d), lambda ib, tbl, pos: (ib, 0, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, pages, bs * g, d), k_store.dtype)
 
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, bs=bs, n_w=w, scale=scale),
+    return pl.pallas_call(
+        functools.partial(_paged_decode_kernel, bs=bs, w=w, g=g,
+                          scale=1.0 / math.sqrt(d)),
         name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, g, w),
-            in_specs=[
-                pl.BlockSpec((1, 1, rep, d),
-                             lambda ib, ig, iw, tbl, pos: (ib, ig, 0, 0)),
-                kv_spec,
-                kv_spec,
-            ],
-            out_specs=pl.BlockSpec((1, 1, rep, d),
-                                   lambda ib, ig, iw, tbl, pos:
-                                   (ib, ig, 0, 0)),
+            grid=(b,),
+            in_specs=[row_spec, any_spec, any_spec],
+            out_specs=row_spec,
             scratch_shapes=[
-                pltpu.VMEM((rep, d), jnp.float32),
-                pltpu.VMEM((rep, 1), jnp.float32),
-                pltpu.VMEM((rep, 1), jnp.float32),
+                buf, buf,
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((h, d), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, g, rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=resolve_interpret(interpret),
-    )(block_table.astype(jnp.int32), pos.astype(jnp.int32), qg,
-      k_store.reshape(n_blocks, bs, g * d),
-      v_store.reshape(n_blocks, bs, g * d))
-    return out.reshape(b, h, d)
+    )(block_table.astype(jnp.int32).reshape(-1), pos.astype(jnp.int32), q,
+      k_store.reshape(n_blocks, bs * g, d),
+      v_store.reshape(n_blocks, bs * g, d))
 
 
 def _head_scale(scales: jnp.ndarray, g) -> jnp.ndarray:

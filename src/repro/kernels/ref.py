@@ -41,3 +41,24 @@ def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray,
     scores = jnp.where(mask, scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), vv)
+
+
+def paged_decode_attention_ref(q: jnp.ndarray, k_store: jnp.ndarray,
+                               v_store: jnp.ndarray, block_table: jnp.ndarray,
+                               pos: jnp.ndarray) -> jnp.ndarray:
+    """Paged GQA decode attention oracle, in f32. q [B,H,D]; k/v_store
+    [N,bs,G,D]; block_table [B,W]; slot b attends over its keys
+    0..pos[b]. Returns [B,H,D] f32."""
+    b, h, d = q.shape
+    _, bs, g, _ = k_store.shape
+    w = block_table.shape[1]
+    k = k_store[block_table].reshape(b, w * bs, g, d).astype(jnp.float32)
+    v = v_store[block_table].reshape(b, w * bs, g, d).astype(jnp.float32)
+    qg = q.reshape(b, g, h // g, d).astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    scores = jnp.einsum("bgrd,bkgd->bgrk", qg, k, precision=hi)
+    scores = scores / math.sqrt(d)
+    valid = jnp.arange(w * bs)[None] <= pos[:, None]
+    scores = jnp.where(valid[:, None, None], scores, -1e30)
+    p = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bgrk,bkgd->bgrd", p, v, precision=hi).reshape(b, h, d)

@@ -379,10 +379,10 @@ def paged_decode_attention(x, params, cfg, cache: dict,
 
     ``use_kernel=True`` routes the gather + score + softmax + value pass
     through ``repro.kernels.paged_decode_attention_grouped`` — one Pallas
-    launch for every slot, KV blocks streamed through the
-    scalar-prefetched block table instead of a materialized
-    ``[B, W*bs, G, hd]`` XLA gather. The XLA path below stays the
-    numerics oracle.
+    launch for every slot, each slot's KV pages copied through the
+    scalar-prefetched block table up to its position instead of a
+    materialized ``[B, W*bs, G, hd]`` XLA gather. The XLA path below
+    stays the numerics oracle.
 
     ``kv_dtype`` other than fp32 quantizes the new token's K/V on
     scatter (codes + per-(token, head) scales, see

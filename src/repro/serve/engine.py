@@ -755,12 +755,16 @@ class ServeEngine:
     def _decode_args(self, active: list[int]) -> dict:
         """The ``decode:tick`` span's args: the tick, the slots decoded
         and the keys they attend over (their cached lengths plus the new
-        token)."""
-        if self.paged:
-            keys = int(self._pos[active].sum()) + len(active)
-        else:
-            keys = len(active) * (self._tick + 1)
-        return {"tick": self._tick, "active": len(active), "keys": keys}
+        token). Paged, also the KV pages the attention kernel streams
+        over every lane (an idle lane, at position 0, streams one) and
+        the ``batch x max_blocks`` pages of the whole block table."""
+        if not self.paged:
+            return {"tick": self._tick, "active": len(active),
+                    "keys": len(active) * (self._tick + 1)}
+        return {"tick": self._tick, "active": len(active),
+                "keys": int(self._pos[active].sum()) + len(active),
+                "pages": int((self._pos // self.block_size + 1).sum()),
+                "grid_pages": self.batch * self.max_blocks}
 
     def run(self, max_ticks: int | None = None, *,
             on_starvation: str = "raise") -> list[Request]:

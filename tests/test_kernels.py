@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (flash_attention,
+                                          paged_decode_attention_grouped)
 from repro.kernels.pim_fp import pim_fp32_mul
 from repro.kernels.pim_mac import pim_mac, pim_matmul
 
@@ -54,6 +55,43 @@ def test_flash_attention_sweep(rng, bshgd, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+def _paged_case(rng, batch, block_size, blocks_per_slot):
+    """A pool, table and positions with ragged lengths: positions 0,
+    bs - 1, bs and W * bs - 1 (a full table), then random ones; the
+    last lane is inactive (position 0 on the scratch block 0, as the
+    engine leaves a freed slot)."""
+    n_blocks = 1 + batch * blocks_per_slot
+    full = blocks_per_slot * block_size
+    pos = np.concatenate([[0, block_size - 1, block_size, full - 1],
+                          rng.integers(0, full, batch - 5), [0]])
+    table = np.zeros((batch, blocks_per_slot), np.int32)
+    ids = rng.permutation(np.arange(1, n_blocks))
+    for s in range(batch - 1):
+        used = pos[s] // block_size + 1
+        table[s, :used] = ids[s * blocks_per_slot:s * blocks_per_slot + used]
+    return n_blocks, jnp.asarray(table), jnp.asarray(pos, jnp.int32)
+
+
+# (bs, W): 128 // bs pages a step clipped to W, so (8, 8) streams the
+# whole table in one step, (8, 20) and (16, 12) end in a partial step
+@pytest.mark.parametrize("bs_w", [(8, 8), (8, 20), (16, 8), (16, 12)])
+@pytest.mark.parametrize("heads", [8, 40])            # rep 1 and 5
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_attention_matches_reference(rng, bs_w, heads, dtype):
+    bs, w = bs_w
+    batch, kv_heads, head_dim = 8, 8, 128
+    n_blocks, table, pos = _paged_case(rng, batch, bs, w)
+    pool = (n_blocks, bs, kv_heads, head_dim)
+    q = jnp.asarray(rng.standard_normal((batch, heads, head_dim)), dtype)
+    k = jnp.asarray(rng.standard_normal(pool), dtype)
+    v = jnp.asarray(rng.standard_normal(pool), dtype)
+    got = paged_decode_attention_grouped(q, k, v, table, pos)
+    want = ref.paged_decode_attention_ref(q, k, v, table, pos)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), rtol=tol, atol=tol)
 
 
 def test_pim_fp32_mul_bitexact_random(rng):

@@ -123,6 +123,11 @@ def test_serve_spans_reach_the_profile_with_args_and_nesting(tmp_path,
     for sp in by_name["decode:tick"]:
         assert {"tick", "active", "keys"} <= set(sp[4])
         assert sp[4]["keys"] >= sp[4]["active"] >= 1
+        # every lane streams at least one page; the pages cover the keys
+        # and never exceed the block table's 2 lanes x 8 blocks
+        assert sp[4]["grid_pages"] == 16
+        assert 2 <= sp[4]["pages"] <= 16
+        assert 4 * sp[4]["pages"] >= sp[4]["keys"]
     assert all("tick" in sp[4] for sp in by_name["tick"])
     ticks = by_name["tick"]
     for name in ("prefill:batch", "decode:tick", "sample:sync", "admit"):

@@ -99,8 +99,8 @@ def test_pim_mac_compiles_at_lenet_optimizer_wave(one_chip, wave):
 
 
 def _paged_operands(one_chip, pool_dtype, *, batch=8, block_size=16,
-                    blocks_per_slot=8):
-    n_blocks = 1 + batch * blocks_per_slot
+                    blocks_per_slot=8, n_blocks=None):
+    n_blocks = n_blocks or 1 + batch * blocks_per_slot
     pool = (n_blocks, block_size, KV_HEADS, HEAD_DIM)
     return dict(
         q=_shape(one_chip, (batch, HEADS, HEAD_DIM), jnp.bfloat16),
@@ -110,8 +110,13 @@ def _paged_operands(one_chip, pool_dtype, *, batch=8, block_size=16,
         pos=_shape(one_chip, (batch,), jnp.int32))
 
 
-def test_paged_decode_attention_compiles_at_qwen_heads(one_chip):
-    o = _paged_operands(one_chip, jnp.bfloat16)
+# a small pool, and the chat benchmark's: 128 slots of up to 144 blocks
+# of 8 tokens in a 12,000-block pool
+@pytest.mark.parametrize("shape", [
+    {}, dict(batch=128, block_size=8, blocks_per_slot=144, n_blocks=12000)],
+    ids=["small", "chat"])
+def test_paged_decode_attention_compiles_at_qwen_heads(one_chip, shape):
+    o = _paged_operands(one_chip, jnp.bfloat16, **shape)
     text = _compile_text(lambda q, k, v, t, p: paged_decode_attention_grouped(
         q, k, v, t, p, interpret=False),
         o["q"], o["pool"], o["pool"], o["table"], o["pos"])
